@@ -12,6 +12,8 @@ Subcommands::
 import sys
 
 from repro.core.api import main
+from repro.launch.cache import enable_compile_cache
 
 if __name__ == "__main__":
+    enable_compile_cache()
     sys.exit(main())

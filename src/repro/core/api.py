@@ -243,7 +243,7 @@ def main(argv=None) -> int:
     aut.add_argument("--cache", default="",
                      help="cache file (default: <store>/autotune_cache.json)")
     aut.add_argument("--interpret", action="store_true",
-                     help="force pallas interpret mode")
+                     help="run the pallas kernel in interpret mode (no TPU)")
     aut.add_argument("--no-baseline", action="store_true",
                      help="skip pinning the winner as the gate baseline")
     aut.add_argument("--force", action="store_true",

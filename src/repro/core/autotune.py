@@ -180,8 +180,8 @@ AUTOTUNE_SCHEMA = ComponentSchema(
         InputSpec("state", int, default=16),
         InputSpec("calls", int, default=3),
         InputSpec("warmup", int, default=1),
-        InputSpec("interpret", bool,
-                  help="force pallas interpret mode (default: auto off-TPU)"),
+        InputSpec("interpret", bool, default=False,
+                  help="run the pallas kernel in interpret mode (no TPU)"),
         InputSpec("block_q", list, default=(), element=int, wrap_scalar=True),
         InputSpec("block_k", list, default=(), element=int, wrap_scalar=True),
         InputSpec("chunk", list, default=(), element=int, wrap_scalar=True),
@@ -224,7 +224,7 @@ def run_autotune(inputs: ComponentInputs, ctx: ComponentContext) -> Dict[str, An
     from repro.core.regression import BaselineManager
     from repro.core.roofline import kernel_terms
     from repro.harnesses.kernel import KERNEL_KNOBS, KernelHarness
-    from repro.hardware import TPU_V5E
+    from repro.hardware import CHIPS_BY_KIND
 
     kernel = inputs["kernel"]
     prefix = inputs.get("prefix") or "autotune"
@@ -233,7 +233,7 @@ def run_autotune(inputs: ComponentInputs, ctx: ComponentContext) -> Dict[str, An
             ("batch", "heads", "seq", "head_dim", "width", "state")}
     harness = KernelHarness(
         kernel=kernel, dtype=inputs["dtype"], calls=int(inputs["calls"]),
-        warmup=int(inputs["warmup"]), interpret=inputs.get("interpret"),
+        warmup=int(inputs["warmup"]), interpret=bool(inputs["interpret"]),
         use_cache=False, **dims)
     skey = harness.shape_key()
     dtype = inputs["dtype"]
@@ -281,13 +281,15 @@ def run_autotune(inputs: ComponentInputs, ctx: ComponentContext) -> Dict[str, An
             errors.append(f"{label}: {res.error or 'no report'}")
             continue
         m = res.report.data[-1].metrics
+        # Peaks of the device the point ran on; none known, no terms.
+        chip = CHIPS_BY_KIND.get(res.report.parameter.get("device_kind", ""))
         points.append({
             "config": cfg,
             "latency_s": float(m["kernel_latency_s"]),
             "achieved_flops": float(m.get("achieved_flops", 0.0)),
             "achieved_bytes_per_s": float(m.get("achieved_bytes_per_s", 0.0)),
-            **kernel_terms(float(m.get("hlo_flops", 0.0)),
-                           float(m.get("hlo_bytes", 0.0)), TPU_V5E),
+            **(kernel_terms(float(m.get("hlo_flops", 0.0)),
+                            float(m.get("hlo_bytes", 0.0)), chip) if chip else {}),
         })
 
     if not points:
@@ -311,7 +313,7 @@ def run_autotune(inputs: ComponentInputs, ctx: ComponentContext) -> Dict[str, An
         kernel, skey, dtype, fp_key,
         best["config"],
         latency_s=best["latency_s"],
-        dominant=best["dominant"],
+        dominant=best.get("dominant"),
         source=prefix,
     )
 
@@ -331,7 +333,7 @@ def run_autotune(inputs: ComponentInputs, ctx: ComponentContext) -> Dict[str, An
         **base,
         "points": points,
         "winner": {"config": best["config"], "latency_s": best["latency_s"],
-                   "dominant": best["dominant"], "confirm": confirm},
+                   "dominant": best.get("dominant"), "confirm": confirm},
         "cache": {**base["cache"], "hit": False, "updated": True,
                   "updates": entry["updates"], "fingerprint_key": fp_key},
         "baseline": baseline_info,

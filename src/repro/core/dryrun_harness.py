@@ -82,6 +82,9 @@ class DryRunHarness(Harness):
             env = dict(os.environ)
             env["PYTHONPATH"] = str(self.repo_root / "src")
             env.update(inj.env)
+            # The child compiles on host devices only; left to pick the
+            # TPU it would contend with this process for the chip.
+            env["JAX_PLATFORMS"] = "cpu"
             proc = subprocess.run(
                 cmd, capture_output=True, text=True, timeout=self.timeout_s, env=env,
                 cwd=self.repo_root,

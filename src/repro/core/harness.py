@@ -232,6 +232,10 @@ class Harness:
     """Adapter interface: everything exaCB needs from a harness."""
 
     name = "abstract"
+    #: Runs its work with JAX in the calling process, on the device JAX
+    #: picks.  A chip serves one process, so such a harness cannot run under
+    #: process workers on a TPU host (``CampaignBroker`` refuses it).
+    uses_device = False
 
     def capabilities(self) -> HarnessCapabilities:
         """Capability declaration; the permissive default keeps third-party
@@ -273,6 +277,7 @@ class ExecHarness(Harness):
     """
 
     name = "exec"
+    uses_device = True
 
     def capabilities(self) -> HarnessCapabilities:
         # Real execution with deterministic artifact digests: every level up
@@ -306,7 +311,8 @@ class ExecHarness(Harness):
             variant=spec.effective_variant(),
             usecase=spec.shape,
             software_version=jax.__version__,
-            parameter={"arch": spec.arch, "injections": inj.describe(), "scale": "smoke"},
+            parameter={"arch": spec.arch, "device_kind": jax.devices()[0].device_kind,
+                       "injections": inj.describe(), "scale": "smoke"},
         )
         cfg = configs.get_smoke(spec.arch)
         for k, v in inj.overrides.items():
@@ -360,7 +366,7 @@ class ExecHarness(Harness):
             nodes=1,
             tasks_per_node=jax.device_count(),
             job_id=f"local-{os.getpid()}",
-            queue="cpu",
+            queue=jax.devices()[0].platform,
             metrics={
                 "step_time_s": float(np.median(times)),
                 "step_time_min_s": float(np.min(times)),
@@ -409,11 +415,5 @@ def _smoke_batch(cfg, kind, B, S, seed):
 
 
 def _cost_analysis(jitted, *args) -> Dict[str, float]:
-    try:
-        compiled = jitted.lower(*args).compile()
-        ca = compiled.cost_analysis()
-        if isinstance(ca, list):  # older jax returns one dict per device
-            ca = ca[0] if ca else {}
-        return {k: float(v) for k, v in ca.items() if isinstance(v, (int, float))}
-    except Exception:
-        return {}
+    ca = jitted.lower(*args).compile().cost_analysis() or {}
+    return {k: float(v) for k, v in ca.items() if isinstance(v, (int, float))}

@@ -563,6 +563,17 @@ def worker_main(worker_id: str, queue_root: str, config: Dict[str, Any]) -> None
 # Broker side
 # ---------------------------------------------------------------------------
 
+def _worker_platform(env: Dict[str, str]) -> str:
+    """The platform JAX picks in a worker spawned with ``env`` on top of
+    this process's environment."""
+    platforms = env.get("JAX_PLATFORMS", os.environ.get("JAX_PLATFORMS", ""))
+    if platforms:
+        return platforms.split(",")[0]
+    import jax
+
+    return jax.default_backend()
+
+
 class CampaignBroker:
     """Materializes cells into a work queue and supervises the worker pool.
 
@@ -653,6 +664,21 @@ class CampaignBroker:
             for idx in range(len(payloads))
         }
 
+    def _refuse_device_harnesses(self, payloads: Sequence[Dict[str, Any]],
+                                 harness: Harness) -> None:
+        """A TPU belongs to one process: workers that each import JAX cannot
+        share it.  Refuse device-bound harnesses (the campaign default and
+        any a payload declares) before anything is spawned; thread mode is
+        the chip path."""
+        from repro import harnesses as harness_families
+
+        declared = [harness_families.from_inputs(p.get("inputs", {})) for p in payloads]
+        bound = sorted({h.name for h in [harness, *declared] if h is not None and h.uses_device})
+        if bound and _worker_platform(self.env) == "tpu":
+            raise PipelineError(
+                f"harness {', '.join(bound)} runs JAX on the TPU, which one process "
+                "holds; process workers cannot share it — use worker_mode: thread")
+
     def run(self, payloads: Sequence[Dict[str, Any]], *, harness: Harness) -> Dict[int, Dict[str, Any]]:
         """Drain ``payloads`` through the worker pool; returns the terminal
         result dict for every cell index (synthesized failure records for
@@ -666,6 +692,7 @@ class CampaignBroker:
         """
         payloads = list(payloads)
         cfg = self._config(harness).to_dict()
+        self._refuse_device_harnesses(payloads, harness)
         try:
             queue = self.materialize(payloads)
             _atomic_json(self.queue_root / WORKER_CONFIG, cfg)

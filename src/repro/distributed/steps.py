@@ -179,8 +179,11 @@ def build_prefill_step(
     strategy: S.Strategy,
     *,
     remat: str = "dots",
+    max_len: int = 0,
 ) -> StepBundle:
-    max_len = shape.seq_len
+    """``max_len``: decode-cache length, when decoding is to follow the
+    ``shape.seq_len``-token prompt (default: the prompt fills the cache)."""
+    max_len = max_len or shape.seq_len
 
     def prefill_step(params, batch):
         return T.prefill(params, cfg, batch, max_len=max_len, remat=remat)

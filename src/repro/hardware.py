@@ -9,7 +9,7 @@ executes at these speeds — it only compiles against the topology.
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Dict, Optional, Tuple
 
 
 @dataclasses.dataclass(frozen=True)
@@ -29,7 +29,8 @@ class ChipSpec:
     power_peak_hbm_w: float = 60.0        # additional W at 100% HBM util
 
 
-# Target system for the assigned meshes (numbers from the task brief).
+# Published peaks of one TPU v5e chip (Google Cloud documentation, "TPU
+# v5e"): 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI.
 TPU_V5E = ChipSpec(
     name="tpu-v5e",
     peak_flops_bf16=197e12,
@@ -39,13 +40,17 @@ TPU_V5E = ChipSpec(
     ici_links=4,
 )
 
+#: Peaks by ``jax.Device.device_kind``.  A device whose kind is missing has
+#: no peaks: callers record no roofline terms for it rather than guess.
+CHIPS_BY_KIND: Dict[str, ChipSpec] = {"TPU v5 lite": TPU_V5E}
+
 
 @dataclasses.dataclass(frozen=True)
 class SystemSpec:
     """A named system = chip model + mesh topology (the paper's 'machine')."""
 
     name: str
-    chip: ChipSpec
+    chip: Optional[ChipSpec]   # None: no published peaks (the CPU host)
     mesh_shape: Tuple[int, ...]
     mesh_axes: Tuple[str, ...]
     # Cross-pod (data-center interconnect) bandwidth per chip, bytes/s.  Only
@@ -74,12 +79,19 @@ MULTI_POD = SystemSpec(
     mesh_axes=("pod", "data", "model"),
 )
 
-# Reduced-scale system used by smoke tests and CPU execution benchmarks.
-CPU_SMOKE = SystemSpec(
-    name="cpu-smoke",
-    chip=dataclasses.replace(TPU_V5E, name="cpu-host"),
-    mesh_shape=(1, 1),
+# One v5e host of four chips, all on the model axis: tensor parallelism for
+# a model one chip cannot hold.
+V5E_2X2 = SystemSpec(
+    name="v5e-2x2",
+    chip=TPU_V5E,
+    mesh_shape=(1, 4),
     mesh_axes=("data", "model"),
 )
 
-SYSTEMS = {s.name: s for s in (SINGLE_POD, MULTI_POD, CPU_SMOKE)}
+# Reduced-scale system used by smoke tests and CPU execution benchmarks.
+CPU_SMOKE = SystemSpec(
+    name="cpu-smoke",
+    chip=None,
+    mesh_shape=(1, 1),
+    mesh_axes=("data", "model"),
+)

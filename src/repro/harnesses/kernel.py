@@ -11,12 +11,11 @@ resolution order:
    matches (kernel, shape key, dtype, hardware fingerprint),
 3. the kernel's shipped defaults.
 
-On CPU the kernels execute in pallas interpret mode, so absolute
-latencies are *not* hardware numbers — they are still monotone in the
-amount of blocking overhead, which is what the sweep ranks.  Achieved
-FLOP/s and bytes/s come from analytic per-kernel counts, not HLO cost
-analysis: interpret mode lowers to scalar loops whose HLO costs say
-nothing about the kernel's arithmetic.
+The kernels are lowered for the TPU; off the chip a caller passes
+``interpret=True``, and latencies are then interpret-mode host times,
+not device numbers.  Achieved FLOP/s and bytes/s come from analytic
+per-kernel counts, not HLO cost analysis: interpret mode lowers to scalar
+loops whose HLO costs say nothing about the kernel's arithmetic.
 """
 
 from __future__ import annotations
@@ -76,6 +75,7 @@ class KernelHarness(Harness):
     """Runs one pallas kernel point; reports latency + achieved roofline."""
 
     name = "kernel"
+    uses_device = True
 
     def __init__(
         self,
@@ -91,7 +91,7 @@ class KernelHarness(Harness):
         calls: int = 3,
         warmup: int = 1,
         causal: bool = True,
-        interpret: Optional[bool] = None,
+        interpret: bool = False,
         use_cache: bool = True,
         cache_path: str = "",
     ):
@@ -109,7 +109,7 @@ class KernelHarness(Harness):
         self.calls = int(calls)
         self.warmup = int(warmup)
         self.causal = bool(causal)
-        self.interpret = interpret
+        self.interpret = bool(interpret)
         self.use_cache = bool(use_cache)
         self.cache_path = str(cache_path)
 
@@ -182,6 +182,7 @@ class KernelHarness(Harness):
             software_version=jax.__version__,
             parameter={
                 "arch": spec.arch,
+                "device_kind": jax.devices()[0].device_kind,
                 "injections": inj.describe(),
                 "scale": "kernel",
                 "kernel": self.kernel,
@@ -212,7 +213,7 @@ class KernelHarness(Harness):
             nodes=1,
             tasks_per_node=jax.device_count(),
             job_id=f"local-{os.getpid()}",
-            queue="cpu",
+            queue=jax.devices()[0].platform,
             metrics={
                 "kernel_latency_s": lat,
                 "kernel_latency_min_s": float(np.min(times)),

@@ -44,6 +44,7 @@ class ServeHarness(Harness):
     """Poisson load generator over ``serve.engine.Engine``."""
 
     name = "serve"
+    uses_device = True
 
     def __init__(
         self,
@@ -109,6 +110,7 @@ class ServeHarness(Harness):
             software_version=jax.__version__,
             parameter={
                 "arch": spec.arch,
+                "device_kind": jax.devices()[0].device_kind,
                 "injections": inj.describe(),
                 "scale": "serve",
                 "batch": batch,
@@ -168,7 +170,7 @@ class ServeHarness(Harness):
             nodes=1,
             tasks_per_node=jax.device_count(),
             job_id=f"local-{os.getpid()}",
-            queue="cpu",
+            queue=jax.devices()[0].platform,
             metrics={
                 "p50_latency_s": float(np.percentile(lat, 50)),
                 "p95_latency_s": float(np.percentile(lat, 95)),

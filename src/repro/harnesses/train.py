@@ -30,6 +30,7 @@ class TrainHarness(Harness):
     """Runs a short smoke-scale training loop per model config."""
 
     name = "train"
+    uses_device = True
 
     def __init__(self, *, steps: int = 3, seq_len: int = 32, global_batch: int = 2):
         self.steps = int(steps)
@@ -70,6 +71,7 @@ class TrainHarness(Harness):
             software_version=jax.__version__,
             parameter={
                 "arch": spec.arch,
+                "device_kind": jax.devices()[0].device_kind,
                 "injections": inj.describe(),
                 "scale": "train",
                 "steps": steps,
@@ -105,7 +107,7 @@ class TrainHarness(Harness):
             nodes=1,
             tasks_per_node=jax.device_count(),
             job_id=f"local-{os.getpid()}",
-            queue="cpu",
+            queue=jax.devices()[0].platform,
             metrics={
                 "step_time_s": float(np.median(steady)),
                 "step_time_min_s": float(np.min(steady)),

@@ -1,15 +1,3 @@
 # OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
 # for compute hot-spots the paper itself optimizes with a custom
 # kernel. Leave this package empty if the paper has none.
-
-
-def tpu_compiler_params(**kwargs):
-    """Construct pallas TPU compiler params across jax versions.
-
-    jax renamed ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams``;
-    accept whichever this installation provides.
-    """
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)

@@ -1,9 +1,8 @@
 """Public entry point for flash attention: jit wrapper + layout handling.
 
 Call ``flash_attention(q, k, v, ...)`` with model-layout tensors
-(B, H, T, D).  On TPU the Pallas kernel runs natively; on CPU the kernel
-body executes in interpret mode (tests) — production CPU/dry-run paths use
-``repro.models.layers.banded_attention`` instead (see ``install()``).
+(B, H, T, D).  The kernel is lowered for the TPU; a caller without one
+passes ``interpret=True``.
 """
 
 from __future__ import annotations
@@ -16,10 +15,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
-
-
-def _on_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
 
 
 def _autotuned_blocks(q_shape, dtype) -> dict:
@@ -48,7 +43,7 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Block resolution happens *outside* the jit: explicit arguments win,
     then the autotune cache, then the shipped 512/512 defaults — so a
@@ -76,12 +71,11 @@ def _flash_attention_jit(
     scale: Optional[float] = None,
     block_q: int = 512,
     block_k: int = 512,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     B, Hq, T, D = q.shape
     Hkv = k.shape[1]
     Dv = v.shape[-1]
-    interpret = (not _on_tpu()) if interpret is None else interpret
     bq = min(block_q, T)
     bk = min(block_k, T)
     pad = (-T) % max(bq, bk)
@@ -106,7 +100,8 @@ def _flash_attention_jit(
     return out.reshape(B, Hq, Tp, Dv)[:, :, :T]
 
 
-def _impl_adapter(q, k, v, *, causal=True, window=None, prefix_len=0, scale=None, **_):
+def _impl_adapter(q, k, v, *, causal=True, window=None, prefix_len=0, scale=None,
+                  interpret=False, **_):
     if prefix_len:
         # Prefix-LM masks are not in the kernel's contract; jnp path handles.
         from repro.models.layers import banded_attention
@@ -114,14 +109,15 @@ def _impl_adapter(q, k, v, *, causal=True, window=None, prefix_len=0, scale=None
         return banded_attention(
             q, k, v, causal=causal, window=window, prefix_len=prefix_len, scale=scale
         )
-    return flash_attention(q, k, v, causal=causal, window=window, scale=scale)
+    return flash_attention(q, k, v, causal=causal, window=window, scale=scale,
+                           interpret=interpret)
 
 
-def install() -> None:
-    """Route model attention through the Pallas kernel (TPU deployments)."""
+def install(*, interpret: bool = False) -> None:
+    """Route model attention through the Pallas kernel."""
     from repro.models import layers as L
 
-    L.set_attention_impl(_impl_adapter)
+    L.set_attention_impl(functools.partial(_impl_adapter, interpret=interpret))
 
 
 def uninstall() -> None:

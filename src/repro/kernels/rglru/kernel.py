@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
-
 
 def _rglru_kernel(a_ref, g_ref, h0_ref, y_ref, h_scr, *, chunk: int):
     ci = pl.program_id(2)  # chunk axis is innermost: it carries the state
@@ -79,7 +77,7 @@ def rglru_pallas(
         out_specs=pl.BlockSpec((1, chunk, block_w), lambda b, w, c: (b, c, w)),
         out_shape=jax.ShapeDtypeStruct((B, T, W), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, block_w), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

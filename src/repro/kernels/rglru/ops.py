@@ -11,10 +11,6 @@ import jax.numpy as jnp
 from repro.kernels.rglru.kernel import rglru_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
-
-
 def _autotuned_blocks(a_shape, dtype) -> dict:
     """Promoted (chunk, block_w) from the autotune cache, when enabled."""
     import os
@@ -34,7 +30,7 @@ def rglru_scan(
     *,
     chunk: Optional[int] = None,
     block_w: Optional[int] = None,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     # Explicit arguments win, then the autotune cache, then 256/512.
     if chunk is None or block_w is None:
@@ -53,10 +49,9 @@ def _rglru_scan_jit(
     *,
     chunk: int = 256,
     block_w: int = 512,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     B, T, W = a.shape
-    interpret = (not _on_tpu()) if interpret is None else interpret
     if h0 is None:
         h0 = jnp.zeros((B, W), a.dtype)
     c = min(chunk, T)

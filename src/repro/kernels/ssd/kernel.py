@@ -7,6 +7,14 @@ the XLA lowering, which materializes per-chunk states.  Each grid step does
 three MXU contractions (CB^T score matrix, intra-chunk y, state update) on
 a (chunk × head_dim) tile plus VPU work for the decay masks.
 
+The in-chunk prefix sum of the log-decay is two matmuls against a
+lower-triangular ones matrix (Mosaic has no ``cumsum``), one per layout:
+``cum_i`` broadcast along columns and ``cum_j`` broadcast along rows.  They
+run at HIGHEST precision because the log-decays are summed over a whole
+chunk and exponentiated.  ``ops.py`` premultiplies ``dt * A`` and passes
+``dt``/``dA`` in both column and row layouts, so no scalar is read from a
+vector block and no vector is transposed in the kernel.
+
 Grid: (B*H, n_chunks).  ``ops.py`` flattens heads and broadcasts groups.
 """
 
@@ -19,17 +27,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _ssd_kernel(
-    x_ref,    # (1, c, P)
-    dt_ref,   # (1, c, 1)   f32 (post-softplus)
-    a_ref,    # (1, 1, 1)   f32 (negative decay rate for this head)
-    b_ref,    # (1, c, N)
-    c_ref,    # (1, c, N)
-    y_ref,    # (1, c, P)
-    s_scr,    # VMEM (N, P) f32 — running inter-chunk state
+    x_ref,       # (1, c, P)
+    dt_c_ref,    # (1, c, 1)  f32 dt (post-softplus), column layout
+    da_c_ref,    # (1, c, 1)  f32 dt * A (log-decay per step), column layout
+    dt_r_ref,    # (1, 1, c)  the same two in row layout
+    da_r_ref,    # (1, 1, c)
+    b_ref,       # (1, c, N)
+    c_ref,       # (1, c, N)
+    y_ref,       # (1, c, P)
+    s_scr,       # VMEM (N, P) f32 — running inter-chunk state
     *,
     chunk: int,
 ):
@@ -40,41 +50,49 @@ def _ssd_kernel(
         s_scr[...] = jnp.zeros_like(s_scr)
 
     x = x_ref[0].astype(jnp.float32)           # (c, P)
-    dt = dt_ref[0].astype(jnp.float32)         # (c, 1)
-    a = a_ref[0, 0, 0]                         # scalar < 0
+    dt_c = dt_c_ref[0]                         # (c, 1)
+    da_c = da_c_ref[0]                         # (c, 1)
+    dt_r = dt_r_ref[0]                         # (1, c)
+    da_r = da_r_ref[0]                         # (1, c)
     Bm = b_ref[0].astype(jnp.float32)          # (c, N)
     Cm = c_ref[0].astype(jnp.float32)          # (c, N)
 
-    dA = dt[:, 0] * a                          # (c,) log-decay per step
-    cum = jnp.cumsum(dA)                       # (c,)
+    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = jj <= ii
+    tri = causal.astype(jnp.float32)           # tri[i, k] = k <= i
+    # cum_i[i, j] = sum_{k<=i} dA_k ;  cum_j[i, j] = sum_{k<=j} dA_k.
+    cum_i = jax.lax.dot_general(
+        tri, jnp.broadcast_to(da_c, (chunk, chunk)), (((1,), (0,)), ((), ())),
+        precision=_HIGHEST, preferred_element_type=jnp.float32)
+    cum_j = jax.lax.dot_general(
+        jnp.broadcast_to(da_r, (chunk, chunk)), tri, (((1,), (1,)), ((), ())),
+        precision=_HIGHEST, preferred_element_type=jnp.float32)
+    cum_c = jnp.max(cum_i, axis=1, keepdims=True)   # (c, 1): every column is cum_i
+    total = jnp.sum(da_c, axis=0, keepdims=True)    # (1, 1): cum at chunk end
 
     # Intra-chunk: scores[i,j] = C_i.B_j * exp(cum_i - cum_j) * dt_j, j<=i.
     scores = jax.lax.dot_general(
         Cm, Bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )                                          # (c, c)
-    li = cum[:, None]
-    lj = cum[None, :]
-    decay = jnp.exp(jnp.minimum(li - lj, 0.0))
-    ii = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    decay = jnp.where(jj <= ii, decay, 0.0)
-    scores = scores * decay * dt[:, 0][None, :]
+    decay = jnp.where(causal, jnp.exp(jnp.minimum(cum_i - cum_j, 0.0)), 0.0)
+    scores = scores * decay * dt_r
     y = jax.lax.dot_general(
         scores, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )                                          # (c, P)
 
     # Inter-chunk: y_i += C_i @ S_prev * exp(cum_i).
-    y = y + jnp.exp(cum)[:, None] * jax.lax.dot_general(
+    y = y + jnp.exp(cum_c) * jax.lax.dot_general(
         Cm, s_scr[...], (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
 
     # State update: S = exp(cum_end) * S_prev + sum_j exp(cum_end-cum_j) dt_j B_j x_j^T.
-    seg = jnp.exp(cum[-1] - cum) * dt[:, 0]    # (c,)
+    seg = jnp.exp(total - cum_c) * dt_c        # (c, 1)
     s_new = jax.lax.dot_general(
-        Bm * seg[:, None], x, (((0,), (0,)), ((), ())),
+        Bm * seg, x, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                          # (N, P)
-    s_scr[...] = jnp.exp(cum[-1]) * s_scr[...] + s_new
+    s_scr[...] = jnp.exp(total) * s_scr[...] + s_new
 
     y_ref[0] = y.astype(y_ref.dtype)
 
@@ -82,7 +100,7 @@ def _ssd_kernel(
 def ssd_pallas(
     x: jax.Array,    # (BH, T, P)
     dt: jax.Array,   # (BH, T)     f32, post-softplus
-    a: jax.Array,    # (BH,)       f32, negative
+    dA: jax.Array,   # (BH, T)     f32, dt * A (A < 0 per head)
     Bm: jax.Array,   # (BH, T, N)  group-broadcast
     Cm: jax.Array,   # (BH, T, N)
     *,
@@ -95,21 +113,22 @@ def ssd_pallas(
     assert T % chunk == 0, "ops.py must pad"
     nc = T // chunk
     kernel = functools.partial(_ssd_kernel, chunk=chunk)
+    col = pl.BlockSpec((1, chunk, 1), lambda b, c: (b, c, 0))
+    row = pl.BlockSpec((1, 1, chunk), lambda b, c: (b, 0, c))
     return pl.pallas_call(
         kernel,
         grid=(BH, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, P), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, chunk, 1), lambda b, c: (b, c, 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, c: (b, 0, 0)),
+            col, col, row, row,
             pl.BlockSpec((1, chunk, N), lambda b, c: (b, c, 0)),
             pl.BlockSpec((1, chunk, N), lambda b, c: (b, c, 0)),
         ],
         out_specs=pl.BlockSpec((1, chunk, P), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, T, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(x, dt[..., None], a[:, None, None], Bm, Cm)
+    )(x, dt[..., None], dA[..., None], dt[:, None, :], dA[:, None, :], Bm, Cm)

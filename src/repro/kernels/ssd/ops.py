@@ -11,10 +11,6 @@ import jax.numpy as jnp
 from repro.kernels.ssd.kernel import ssd_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.devices()[0].platform == "tpu"
-
-
 def _autotuned_blocks(x_shape, n_state, dtype) -> dict:
     """Promoted chunk size from the autotune cache, when enabled."""
     import os
@@ -36,7 +32,7 @@ def ssd_scan(
     Cm: jax.Array,   # (B, T, G, N)
     *,
     chunk: Optional[int] = None,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     # Explicit argument wins, then the autotune cache, then 256.
     if chunk is None:
@@ -54,12 +50,11 @@ def _ssd_scan_jit(
     Cm: jax.Array,
     *,
     chunk: int = 256,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     B, T, H, P = x.shape
     G, N = Bm.shape[2], Bm.shape[3]
     rep = H // G
-    interpret = (not _on_tpu()) if interpret is None else interpret
     c = min(chunk, T)
     pad = (-T) % c
     if pad:
@@ -73,5 +68,5 @@ def _ssd_scan_jit(
     af = jnp.broadcast_to(A[None, :], (B, H)).reshape(B * H).astype(jnp.float32)
     Bh = jnp.repeat(Bm.transpose(0, 2, 1, 3), rep, axis=1).reshape(B * H, Tp, N)
     Ch = jnp.repeat(Cm.transpose(0, 2, 1, 3), rep, axis=1).reshape(B * H, Tp, N)
-    y = ssd_pallas(xf, dtf, af, Bh, Ch, chunk=c, interpret=interpret)
+    y = ssd_pallas(xf, dtf, dtf * af[:, None], Bh, Ch, chunk=c, interpret=interpret)
     return y.reshape(B, H, Tp, P).transpose(0, 2, 1, 3)[:, :T]

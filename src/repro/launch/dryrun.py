@@ -86,8 +86,6 @@ def run_cell(
 
     mem = compiled.memory_analysis()
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):
-        ca = ca[0] if ca else {}
     text = compiled.as_text()
     cost = hlo.analyze(text, n_devices=system.n_chips)
 

@@ -8,32 +8,29 @@ see the single real CPU device.
 
 from __future__ import annotations
 
-from typing import Optional
-
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
-from repro.hardware import SYSTEMS, SystemSpec
+from repro.hardware import SystemSpec
+
+
+def _auto_mesh(shape, axes) -> Mesh:
+    """Mesh over the first ``prod(shape)`` devices whose axes GSPMD
+    partitions (``with_sharding_constraint`` in ``distributed.constraints``
+    needs Auto axes; ``jax.make_mesh`` defaults to Explicit)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_mesh(system: SystemSpec) -> Mesh:
-    return jax.make_mesh(system.mesh_shape, system.mesh_axes)
+    return _auto_mesh(system.mesh_shape, system.mesh_axes)
 
 
 def make_smoke_mesh() -> Mesh:
-    """1x1 mesh over the single local device (tests, CPU benches)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
-
-
-def system_for(name: str) -> SystemSpec:
-    return SYSTEMS[name]
-
-
-def required_devices(system: SystemSpec) -> int:
-    return system.n_chips
+    """1x1 mesh over device 0 (tests, CPU benches, the one-chip reference)."""
+    return _auto_mesh((1, 1), ("data", "model"))

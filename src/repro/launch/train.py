@@ -63,4 +63,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     sys.exit(main())

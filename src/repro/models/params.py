@@ -39,7 +39,7 @@ Pytree = Any
 class ParamSpec:
     shape: Tuple[int, ...]
     logical_axes: Tuple[Optional[str], ...]
-    init: str = "fan_in"      # fan_in | zeros | ones | rglru_a | ssd_a_log | ssd_dt_bias | normal_<std>
+    init: str = "fan_in"      # fan_in | zeros | ones | rglru_a | ssd_a_log | ssd_dt_bias | std_<std>
     dtype: str = "bfloat16"
 
     def __post_init__(self):
@@ -210,7 +210,7 @@ def param_specs(cfg: ModelConfig) -> Dict[str, Any]:
     out: Dict[str, Any] = {}
     if cfg.input_mode == "tokens":
         out["embed"] = {
-            "table": ParamSpec((v, d), ("vocab", "embed"), "normal_1.0")
+            "table": ParamSpec((v, d), ("vocab", "embed"), "std_1.0")
         }
     n_full, rem = block_layout(cfg)
     blocks: Dict[str, Any] = {}
@@ -310,6 +310,15 @@ def non_embedding_param_count(cfg: ModelConfig, active_only: bool = False) -> in
 # Initialization
 # ---------------------------------------------------------------------------
 
+def _uniform_std(key: jax.Array, shape, std: float) -> jax.Array:
+    """Zero-mean uniform draw with standard deviation ``std``.  Uniform, not
+    normal: on a TPU v5e a normal draw of one full-width leaf compiles in
+    ~30 s against ~5 s for a uniform one, and init compiles a program per
+    leaf shape."""
+    a = std * math.sqrt(3.0)
+    return jax.random.uniform(key, shape, jnp.float32, -a, a)
+
+
 def _init_leaf(key: jax.Array, s: ParamSpec) -> jax.Array:
     dt = jnp.dtype(s.dtype)
     if s.init == "zeros":
@@ -328,34 +337,38 @@ def _init_leaf(key: jax.Array, s: ParamSpec) -> jax.Array:
     if s.init == "ssd_dt_bias":
         dtv = jax.random.uniform(key, s.shape, jnp.float32, 1e-3, 1e-1)
         return jnp.log(jnp.expm1(dtv)).astype(dt)  # inverse softplus
-    if s.init.startswith("normal_"):
-        std = float(s.init.split("_", 1)[1])
-        return (jax.random.normal(key, s.shape, jnp.float32) * std).astype(dt)
+    if s.init.startswith("std_"):
+        return _uniform_std(key, s.shape, float(s.init.split("_", 1)[1])).astype(dt)
     if s.init == "fan_in":
         # Fan-in = product of all axes left of the last "output block".
         # Heuristic: treat the first axis (after any 'layers' stack) as input.
         shape = s.shape
         offset = 1 if (s.logical_axes and s.logical_axes[0] == "layers") else 0
         fan_in = shape[offset] if len(shape) > offset else 1
-        std = 1.0 / math.sqrt(max(1, fan_in))
-        return (jax.random.normal(key, s.shape, jnp.float32) * std).astype(dt)
+        return _uniform_std(key, s.shape, 1.0 / math.sqrt(max(1, fan_in))).astype(dt)
     raise ValueError(s.init)
 
 
-def init_params(cfg: ModelConfig, key: jax.Array) -> Pytree:
-    """Materialize a parameter tree (smoke-scale use only).
+def init_params(cfg: ModelConfig, key: jax.Array, shardings: Optional[Pytree] = None) -> Pytree:
+    """Materialize a parameter tree.
 
-    Per-leaf keys derive from a CRC of the path — deterministic across
-    processes (readiness L3 requires bit-reproducible init).
+    Each leaf is drawn by its own jitted program, so the f32 draw fuses into
+    the leaf's dtype and never exists whole beside the resident weights (at
+    full width it would not fit on one chip).  ``shardings`` (a tree like
+    ``distributed.sharding.param_shardings``) places every leaf as it is
+    drawn; without it leaves land on the default device.  Per-leaf keys
+    derive from a CRC of the path — deterministic across processes
+    (readiness L3 requires bit-reproducible init).
     """
     import zlib
 
     specs = param_specs(cfg)
-    flat = list(iter_specs(specs))
+    placement = flatten(shardings) if shardings is not None else {}
     leaves = {}
-    for path, s in flat:
+    for path, s in iter_specs(specs):
         k = jax.random.fold_in(key, zlib.crc32(path.encode()) & 0x7FFFFFFF)
-        leaves[path] = _init_leaf(k, s)
+        draw = jax.jit(_init_leaf, static_argnums=1, out_shardings=placement.get(path))
+        leaves[path] = draw(k, s)
     return unflatten(leaves)
 
 

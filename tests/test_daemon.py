@@ -351,6 +351,8 @@ def test_daemon_status_reports_lag_and_due(tmp_path):
 def _cli_env():
     env = dict(os.environ)
     env["PYTHONPATH"] = str(REPO / "src")
+    # The CLI keeps a compile cache in the checkout; tests leave it off.
+    env["JAX_ENABLE_COMPILATION_CACHE"] = "false"
     return env
 
 

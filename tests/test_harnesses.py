@@ -126,6 +126,9 @@ def test_kernel_harness_reports_latency_and_roofline_inputs():
     assert rep.parameter["blocks"] == {"block_q": 16, "block_k": 16}
     assert rep.parameter["blocks_source"] == "injections"
     assert rep.parameter["kernel_shape"] == "B1.H2.T32.D8"
+    # The report names the device it ran on.
+    assert rep.data[-1].queue == "cpu"
+    assert rep.parameter["device_kind"] == "cpu"
 
 
 def test_kernel_harness_default_blocks_without_cache():
@@ -279,7 +282,9 @@ def test_autotune_component_sweep_promote_and_noop(tmp_path):
     out = resolved.run(inputs, ctx)
     assert len(out["points"]) == 2
     assert out["winner"]["config"]["block_q"] in (16, 32)
-    assert out["points"][0]["dominant"] in ("compute", "memory")
+    # The CPU host has no entry in the peaks table: no roofline terms.
+    assert "dominant" not in out["points"][0]
+    assert out["winner"]["dominant"] is None
     assert (tmp_path / "store" / "autotune_cache.json").exists()
 
     from repro.core.regression import BaselineManager

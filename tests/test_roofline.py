@@ -5,7 +5,7 @@ import pytest
 from repro import configs
 from repro.core import roofline
 from repro.distributed.hlo import HloCost
-from repro.hardware import SINGLE_POD
+from repro.hardware import CHIPS_BY_KIND, SINGLE_POD, TPU_V5E
 
 
 def _mk(cost_kw, shape_kind="train", arch="glm4-9b", gb=256, seq=4096):
@@ -16,6 +16,12 @@ def _mk(cost_kw, shape_kind="train", arch="glm4-9b", gb=256, seq=4096):
         seq_len=seq, global_batch=gb, system=SINGLE_POD, strategy="tp_dp",
         cost=cost, hbm_required=8e9, state_bytes=0.0,
     )
+
+
+@pytest.mark.parametrize("kind,chip", [("TPU v5 lite", TPU_V5E), ("cpu", None),
+                                       ("TPU v4", None)])
+def test_peaks_are_looked_up_by_device_kind(kind, chip):
+    assert CHIPS_BY_KIND.get(kind) is chip
 
 
 def test_terms_and_dominant():

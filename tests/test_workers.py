@@ -257,6 +257,29 @@ def test_broker_synthesizes_failures_for_lost_cells(tmp_path):
     assert all(r["readiness"] == 0 and r["error"] for r in results.values())
 
 
+@pytest.mark.parametrize("declared", [False, True])
+def test_broker_refuses_device_harness_on_tpu(tmp_path, monkeypatch, declared):
+    """On a TPU host a device-bound harness (the campaign default, or one a
+    payload declares) is refused before any worker or queue exists."""
+    from repro.core import workers
+    from repro.core.harness import ExecHarness
+
+    monkeypatch.setattr(workers, "_worker_platform", lambda env: "tpu")
+    store = ResultStore(tmp_path / "s")
+    broker = CampaignBroker(store, workers=1, name="chip")
+    payloads = _payloads(1)
+    harness = SpinHarness(iters=10)
+    if declared:
+        payloads[0]["inputs"].update({"harness": "kernel"})
+    else:
+        harness = ExecHarness()
+    with pytest.raises(PipelineError, match="worker_mode: thread"):
+        broker.run(payloads, harness=harness)
+    assert not broker.queue_root.exists() and broker.processes == []
+    # A host-only harness may still use process workers there.
+    broker._refuse_device_harnesses(_payloads(1), SpinHarness(iters=10))
+
+
 # ---------------------------------------------------------------------------
 # crash recovery: SIGKILL mid-cell → lease expiry → reclaim → retry
 # ---------------------------------------------------------------------------
