@@ -1,0 +1,8 @@
+"""Jitted steps: operations of the requests served (``chipbench.flops``)
+over the window's span, the chips and their peak, in percent."""
+
+from chipbench import readings as R
+
+
+def read(run):
+    return R.mfu_percent(run)
