@@ -8,13 +8,17 @@ full-config prefill/decode steps for the production mesh.
     PYTHONPATH=src python -m repro.launch.serve --arch starcoder2-3b --requests 8
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-32b --smoke
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-32b --dry-run
+
+A local run prints what the engine's recorder (``repro.serve.telemetry``)
+holds: tokens/s over the ``generate`` calls, the share of decode slot-steps
+that kept a token, and the p50/p90 of time to first token and of the gap
+between tokens.  That is how an operator reads the engine's recorder.
 """
 
 from __future__ import annotations
 
 import argparse
 import sys
-import time
 from typing import List
 
 import numpy as np
@@ -48,6 +52,26 @@ def make_requests(cfg, n: int, *, prompt_len: int, max_new_tokens: int,
     ]
 
 
+def summary(snap: dict, batch: int) -> str:
+    """Tokens/s over the ``engine.generate`` spans, decode slot use (the
+    ``live`` slots of the ``engine.step`` spans over ``batch`` slots each),
+    and the p50/p90 of time to first token and of the gap between tokens,
+    from a ``repro.serve.telemetry`` snapshot."""
+    tokens = sum(len(r["token_ns"]) for r in snap["requests"])
+    secs = sum(s.end_ns - s.start_ns for s in snap["spans"] if s.name == "engine.generate") * 1e-9
+    steps = [s.ids["live"] for s in snap["spans"] if s.name == "engine.step"]
+    slot_use = 100.0 * sum(steps) / max(len(steps) * batch, 1)
+    reqs = [r for r in snap["requests"] if r["token_ns"]]
+    ttft = [(r["token_ns"][0] - r["start_ns"]) * 1e-9 for r in reqs]
+    itl = [g * 1e-9 for r in reqs for g in np.diff(r["token_ns"])]
+
+    def p50_p90(v):
+        return "p50 {:.4f}s p90 {:.4f}s".format(*np.percentile(v, [50, 90])) if v else "none"
+
+    return (f"{len(reqs)} requests, {tokens} tokens, {secs:.2f}s -> {tokens / secs:.1f} tok/s; "
+            f"decode slot use {slot_use:.1f}%; ttft {p50_p90(ttft)}; itl {p50_p90(itl)}")
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True)
@@ -72,6 +96,7 @@ def main(argv=None):
         return 0 if ok else 1
 
     from repro import configs
+    from repro.serve import telemetry
 
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get_config(args.arch)
     try:
@@ -80,11 +105,9 @@ def main(argv=None):
         ap.error(str(e))
     reqs = make_requests(cfg, args.requests, prompt_len=args.prompt_len,
                          max_new_tokens=args.max_new_tokens)
-    t0 = time.perf_counter()
-    outs = eng.generate(reqs)
-    dt = time.perf_counter() - t0
-    total = sum(len(c.tokens) for c in outs)
-    print(f"{len(reqs)} requests, {total} tokens, {dt:.2f}s -> {total/dt:.1f} tok/s")
+    telemetry.reset()
+    eng.generate(reqs)
+    print(summary(telemetry.snapshot(), eng.batch))
     return 0
 
 
