@@ -9,6 +9,11 @@ jitted step uses the MAXIMUM position for cache masking, which is correct
 but admits some wasted attention span for ragged batches — the paper-style
 time-series benchmark tracks exactly this kind of serving regression.
 
+Between steps the decode state is held with each leaf's dimensions in the
+order of the device layout that the decode step's compiler picks for it, and
+each step consumes the state it is given (donation): prefill hands it over
+in that order, and the step updates it in place.
+
 Greedy and temperature sampling supported; everything is seeded and
 deterministic (readiness L3).  Each wave records its spans and its
 requests' token times in ``repro.serve.telemetry``.
@@ -18,11 +23,12 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Format, Layout
 
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
@@ -47,6 +53,47 @@ class Completion:
     prompt_len: int
 
 
+def _reorder(state: Pytree, orders: List[Tuple[int, ...]], *, inverse: bool = False) -> Pytree:
+    """Each leaf's dimensions put in its order (or back, with ``inverse``)."""
+    leaves, tree = jax.tree.flatten(state)
+    return tree.unflatten([jnp.transpose(a, np.argsort(o) if inverse else o)
+                           for a, o in zip(leaves, orders)])
+
+
+def compile_decode_step(cfg: ModelConfig, params: Pytree, *, batch: int,
+                        max_len: int) -> Tuple[jax.stages.Compiled, List[Tuple[int, ...]]]:
+    """``jit_decode_step`` for ``params`` (arrays, or shapes with their
+    shardings), compiled ahead of time, and the order it keeps the decode
+    state in: for each leaf, the model's dimensions from major to minor.
+
+    The order is the device layout the compiler picks for each leaf when the
+    state is donated and its layout left to it.  The step takes and returns
+    every leaf transposed into that order, in the default layout, so the
+    bytes sit as its loop wants them: the transposes are free, the donated
+    state is updated in place, and no layout but the default crosses a
+    program boundary.  Call it as ``(params, state, {"tokens": (batch, 1)
+    int32}, idx int32)``; the state passed in is consumed."""
+    placement = jax.tree.leaves(params)[0].sharding
+    model_state = jax.eval_shape(lambda: T.init_decode_state(cfg, batch, max_len))
+    inputs = ({"tokens": jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=placement)},
+              jax.ShapeDtypeStruct((), jnp.int32, sharding=placement))
+
+    auto = jax.tree.map(lambda _: Format(Layout.AUTO, placement), model_state)
+    probe = jax.jit(lambda p, s, b, i: T.decode_step(p, cfg, s, b, i),
+                    in_shardings=(None, auto, None, None), out_shardings=(None, auto),
+                    donate_argnums=(1,)).lower(params, model_state, *inputs).compile()
+    orders = [f.layout.major_to_minor for f in jax.tree.leaves(probe.input_formats[0][1])]
+
+    def decode_step(p, s, b, i):
+        logits, s = T.decode_step(p, cfg, _reorder(s, orders, inverse=True), b, i)
+        return logits, _reorder(s, orders)
+
+    state = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=placement),
+                         jax.eval_shape(lambda s: _reorder(s, orders), model_state))
+    step = jax.jit(decode_step, donate_argnums=(1,)).lower(params, state, *inputs).compile()
+    return step, orders
+
+
 class Engine:
     def __init__(
         self,
@@ -65,15 +112,17 @@ class Engine:
         self.key = jax.random.key(seed)
 
         # Named functions, so that the device trace names the programs
-        # ``jit_prefill`` and ``jit_decode_step``.
+        # ``jit_prefill`` and ``jit_decode_step``.  Prefill hands its state
+        # over in the order the decode step keeps it in, and places its
+        # outputs where the step's are, so that the host's ops on either
+        # step's tokens compile once.
+        self._decode, orders = compile_decode_step(cfg, params, batch=batch, max_len=max_len)
+
         def prefill(p, b):
-            return T.prefill(p, cfg, b, max_len=max_len, remat="none")
+            logits, state = T.prefill(p, cfg, b, max_len=max_len, remat="none")
+            return logits, _reorder(state, orders)
 
-        def decode_step(p, s, b, i):
-            return T.decode_step(p, cfg, s, b, i)
-
-        self._prefill = jax.jit(prefill)
-        self._decode = jax.jit(decode_step)
+        self._prefill = jax.jit(prefill, out_shardings=jax.tree.leaves(params)[0].sharding)
 
     # -- batched offline generation (all requests same length budget) --
     def generate(self, requests: List[Request]) -> List[Completion]:

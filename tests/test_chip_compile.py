@@ -7,7 +7,9 @@ for a ``v5e:2x2`` topology description.  Nothing runs; this proves only that
 the chip's compiler accepts the programs and that they fit its memory.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -120,16 +122,43 @@ def test_starcoder2_prefill_compiles_with_flash_kernel(one_chip, starcoder2):
     _assert_kernel_fits(compiled)
 
 
-def test_starcoder2_decode_step_fits_one_chip(one_chip, starcoder2):
+@pytest.fixture(scope="module")
+def starcoder2_decode(starcoder2):
+    """The engine's decode step, compiled through its own path, with the
+    order it keeps its state in, and the shapes of the model's state."""
     from repro.models import transformer as T
+    from repro.serve.engine import compile_decode_step
 
     cfg, params = starcoder2
-    state = jax.tree.map(
-        lambda s: _sds(s.shape, s.dtype, one_chip),
-        jax.eval_shape(lambda: T.init_decode_state(cfg, 8, 2048)))
-    compiled = _compile(
-        lambda p, s, b, i: T.decode_step(p, cfg, s, b, i),
-        params, state, {"tokens": _sds((8, 1), "int32", one_chip)},
-        _sds((), "int32", one_chip))
+    compiled, orders = compile_decode_step(cfg, params, batch=8, max_len=2048)
+    state = jax.eval_shape(lambda: T.init_decode_state(cfg, 8, 2048))
+    return compiled, orders, params, state
+
+
+def test_starcoder2_decode_step_fits_one_chip(starcoder2_decode):
+    compiled = starcoder2_decode[0]
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_starcoder2_decode_state_stays_in_place(starcoder2_decode):
+    """The step keeps its stacked cache sequence-major, not in the model's
+    batch-major order; every state leaf is aliased from input to output;
+    and the entry computation copies none of them, so there is no transpose
+    into the loop's layout and back around the step."""
+    compiled, orders, params, state = starcoder2_decode
+    leaves = jax.tree.leaves(state)
+    assert {a.shape for a in leaves} == {(30, 8, 2, 2048, 128)}
+    assert orders == [(0, 3, 2, 1, 4)] * len(leaves)
+    text = compiled.as_text()
+    aliased = {int(p) for p in re.findall(r"\{\d+\}: \((\d+), \{\}, \w+-alias\)",
+                                          text.splitlines()[0])}
+    first = len(jax.tree.leaves(params))
+    assert aliased == set(range(first, first + len(leaves)))
+    assert compiled.memory_analysis().alias_size_in_bytes == sum(
+        math.prod(a.shape) * a.dtype.itemsize for a in leaves)
+    entry = text[text.index("\nENTRY"):]
+    entry = entry[: entry.index("\n}\n")]
+    copied = [math.prod(int(d) for d in m.group(1).split(",") if d)
+              for m in re.finditer(r"= \(?\w+\[([\d,]*)\]\S* copy(?:-start)?\(", entry)]
+    assert copied and max(copied) < math.prod(leaves[0].shape)
