@@ -38,17 +38,18 @@ CONTROL_SEEDS = 4
 
 def readings(cell, seed: int, seconds: float, rehearse: bool, control: bool) -> dict:
     """The program's and (with ``control``) the control's numbers for one seed."""
-    model = PG.model_sizes(cell.config, rehearse)
+    fam = cell.family
+    model = fam.sizes(cell.config, rehearse)
     driver = cell.driver(cell, seed=seed, seconds=seconds, rehearse=rehearse)
     driver.setup()
     record = driver.run(Tracer(enabled=False, start_s=0, seconds=0))
     samples = driver.release(record)
     tokens, rows = PG.reference_inputs(samples, driver.max_out)
-    ref = R.logits(model, seed, tokens, rows)
+    ref = fam.reference_logits(model, seed, tokens, rows)
     out = {"seed": seed, "tokens_compared": int(sum(len(s.served) for s in samples)),
            "program": R.compare([s.served for s in samples], ref)}
     if control:
-        ctl = R.logits(model, seed, tokens, rows, quantize="fp8")
+        ctl = fam.reference_logits(model, seed, tokens, rows, quantize="fp8")
         out["control"] = R.control_readings(ref, ctl, [len(s.served) for s in samples])
     return out
 

@@ -7,9 +7,9 @@ and sleeps until the next is due when none is.  Each request is timed
 from when it was due to the return of the call that served it; the
 window's requests still waiting when it closes are served and counted.
 
-The flash-attention kernel serves the prefill, as installed by
-``repro.kernels.flash_attention.ops.install``.  The check compares the
-tokens that ``Engine.generate`` served for a seeded sample of requests.
+The cell's family builds the program's configuration, draws the weights
+and installs its kernels.  The check compares the tokens that
+``Engine.generate`` served for a seeded sample of requests.
 """
 
 from __future__ import annotations
@@ -22,34 +22,27 @@ import numpy as np
 
 from chipbench import program as PG
 from chipbench import traffic as TR
-from chipbench import weights as W
-
-# Substring of the flash kernel's op names in the device trace.
-KERNEL = "flash"
 
 
 class Driver:
-    kernel = KERNEL
-
     def __init__(self, cell, *, seed: int, seconds: float, rehearse: bool):
         self.cell, self.seed, self.seconds, self.rehearse = cell, seed, seconds, rehearse
         wl = cell.workload
         over = wl.get("rehearse", {}) if rehearse else {}
         self.engine_cfg = {**wl["engine"], **over.get("engine", {})}
         self.traffic = {**cell.traffic, **over.get("traffic", {})}
-        self.model = PG.model_sizes(cell.config, rehearse)
+        self.model = cell.family.sizes(cell.config, rehearse)
         self.batch = int(self.engine_cfg["batch"])
 
     def setup(self) -> None:
         import jax
 
-        from repro.kernels.flash_attention import ops as fa
         from repro.serve.engine import Engine, Request
 
-        cfg = PG.repo_config(self.cell.config["name"], self.model)
-        fa.install(interpret=self.rehearse)
+        cfg = self.cell.family.repo_config(self.cell.config["name"], self.model)
+        self.cell.family.install_kernels(interpret=self.rehearse)
         with PG.phase("weights"):
-            self.params = W.make_params(self.model, self.seed)
+            self.params = self.cell.family.make_params(self.model, self.seed)
             jax.block_until_ready(self.params)
         self.eng = Engine(cfg, self.params, batch=self.batch,
                           max_len=int(self.engine_cfg["max_len"]), seed=0)
@@ -126,12 +119,10 @@ class Driver:
     def release(self, record: dict) -> List[PG.Sample]:
         """Free the program's device state; the sequences to compare (a
         request that was not served leaves its sample without tokens)."""
-        from repro.kernels.flash_attention import ops as fa
-
         samples = [PG.Sample(prompt=self.requests[uid].prompt,
                              served=record["requests"][uid]["tokens"])
                    for uid in self.check_uids]
-        fa.uninstall()
+        self.cell.family.uninstall_kernels()
         del self.eng, self.params
         gc.collect()
         return samples

@@ -3,7 +3,10 @@ a cell, a driver or a metric is added by adding a file:
 
 - ``BENCHMARK.json`` at the root: the cell's entry (its config and traffic
   names, chips) and the metrics it reports, with their units;
-- ``chipbench/configs/<config>.json``: sizes and deployment;
+- ``chipbench/configs/<config>.json``: sizes and deployment, and in
+  ``"family"`` the name of its architecture's module;
+- ``chipbench/families/<family>.py``: sizes, the program's configuration,
+  weights, reference, operation counts and kernels of one architecture;
 - ``chipbench/traffic/<traffic>.json``: the generator's parameters;
 - ``chipbench/workloads/<cell>.json``: driver, engine settings, check limits;
 - ``chipbench/drivers/<driver>.py``: a ``Driver`` class;
@@ -16,6 +19,7 @@ import dataclasses
 import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -52,6 +56,19 @@ class Cell:
     workload: dict
     driver: type
     metrics: List[Metric]
+    family: ModuleType
+
+
+def load_family(config_file: Path) -> ModuleType:
+    """The module in ``families/`` beside ``configs/`` that the configuration
+    file names in ``"family"``; a file without the key, or naming no module,
+    is refused."""
+    name = _json(config_file).get("family")
+    path = Path(config_file).parents[1] / "families" / f"{name}.py"
+    if not isinstance(name, str) or not path.is_file():
+        raise ValueError(f"{config_file}: 'family' must name a module in "
+                         f"{PACKAGE}/families/, not {name!r}")
+    return load_module(path, f"{PACKAGE}_family_{name}")
 
 
 def _reports(metric: dict, cell: str) -> bool:
@@ -76,10 +93,11 @@ def load_cell(name: str, *, trace: bool, root: Path = ROOT) -> Cell:
                       load_module(pkg / "metrics" / f"{m['name']}.py",
                                   f"{PACKAGE}_metric_{m['name']}").read)
                for m in specs if _reports(m, name)]
-    return Cell(name=name, chips=int(entry["chips"]),
-                config=_json(pkg / "configs" / f"{entry['config']}.json"),
+    config_file = pkg / "configs" / f"{entry['config']}.json"
+    return Cell(name=name, chips=int(entry["chips"]), config=_json(config_file),
                 traffic=_json(pkg / "traffic" / f"{entry['traffic']}.json"),
-                workload=workload, driver=driver, metrics=metrics)
+                workload=workload, driver=driver, metrics=metrics,
+                family=load_family(config_file))
 
 
 def peaks_for(kind: str, root: Path = ROOT) -> Dict[str, float]:

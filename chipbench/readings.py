@@ -7,8 +7,6 @@ from typing import Optional
 
 import numpy as np
 
-from chipbench import flops as F
-
 
 def percentile(values, q: float) -> Optional[float]:
     """The ``q``-th percentile (linear between ranks); a failed item counts
@@ -44,8 +42,8 @@ def span_s(run) -> float:
 
 def work_flops(run) -> float:
     """Operations of the real work served: each served request's prefill and
-    decode steps."""
-    return float(sum(F.sequence_flops(run.model, r["n_prompt"], r["n_out"])
+    decode steps, as the cell's family counts them."""
+    return float(sum(run.family.sequence_flops(run.model, r["n_prompt"], r["n_out"])
                      for r in run.record["requests"] if r["ok"]))
 
 

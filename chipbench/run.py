@@ -7,15 +7,15 @@
 A run makes the cell's weights and traffic from ``--seed``, warms every
 shape the window uses (set-up), measures for ``--seconds``, serves what is
 still due, then frees the program's state and compares what the timed
-path produced with the plain reference (``chipbench/reference.py``).  The
-last line of standard output is one JSON object: ``correct``,
-``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics, or
-with ``--trace 1`` its per-layer metrics, read from a profiler trace of a
-stretch of the window), ``device`` and, last, ``checks``: each number
-compared with its limit.  The checks are also the last lines of standard
-error.  Lines before the last report set-up phases, how late the load
-generator ran, compiles inside the window and device memory; they are not
-metrics.
+path produced with the plain reference (the ``reference_logits`` of the
+cell's family).  The last line of standard output is one JSON object:
+``correct``, ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end
+metrics, or with ``--trace 1`` its per-layer metrics, read from a profiler
+trace of a stretch of the window), ``device`` and, last, ``checks``: each
+number compared with its limit.  The checks are also the last lines of
+standard error.  Lines before the last report set-up phases, how late the
+load generator ran, compiles inside the window and device memory; they are
+not metrics.
 
 It runs on the TPU it is started on and fails, printing no result, where
 JAX finds no TPU, fewer chips than the cell asks for, or a device kind
@@ -39,6 +39,7 @@ import json  # noqa: E402
 import sys  # noqa: E402
 import traceback  # noqa: E402
 from pathlib import Path  # noqa: E402
+from types import ModuleType  # noqa: E402
 from typing import Optional  # noqa: E402
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -59,11 +60,13 @@ def info(tag: str, **kv) -> None:
 @dataclasses.dataclass
 class Run:
     """What a metric reader reads: the driver's record of the window, the
-    reduced trace (``None`` untraced), the sizes, the chip's peaks (``None``
-    when rehearsing), and the set-up time."""
+    reduced trace (``None`` untraced), the sizes and the family that counts
+    their operations, the chip's peaks (``None`` when rehearsing), and the
+    set-up time."""
     record: dict
     trace: Optional[dict]
     model: dict
+    family: ModuleType
     peak: Optional[dict]
     chips: int
     setup_s: float
@@ -133,7 +136,8 @@ def check(cell, model: dict, seed: int, samples, max_out: int, rehearse: bool) -
     if any(s.served is None for s in samples):
         return {k: {"value": None, "limit": v} for k, v in limits.items()}
     tokens, rows = PG.reference_inputs(samples, max_out)
-    got = R.compare([s.served for s in samples], R.logits(model, seed, tokens, rows))
+    ref = cell.family.reference_logits(model, seed, tokens, rows)
+    got = R.compare([s.served for s in samples], ref)
     return {k: {"value": got.get(k), "limit": v} for k, v in limits.items()}
 
 
@@ -159,10 +163,9 @@ def main(argv=None) -> int:
     kind = devices[0].device_kind
     counter = CompileCounter()
     jax.monitoring.register_event_duration_secs_listener(counter)
-    from chipbench import program as PG
     from chipbench.trace import Tracer
 
-    model = PG.model_sizes(cell.config, args.rehearse)
+    model = cell.family.sizes(cell.config, args.rehearse)
     driver = cell.driver(cell, seed=args.seed, seconds=args.seconds, rehearse=args.rehearse)
     driver.setup()
     setup_s = time.perf_counter() - T_START
@@ -175,7 +178,7 @@ def main(argv=None) -> int:
     counter.on = False
     info("window", compiles_in_window=counter.count,
          generator_lag_s=record.get("generator_lag_s"))
-    trace = tracer.summary(kernel=driver.kernel)
+    trace = tracer.summary(kernel=cell.family.KERNEL)
     mem = _peak_bytes(devices)
     info("memory", peak_bytes_in_use=mem)
 
@@ -189,8 +192,8 @@ def main(argv=None) -> int:
     correct = (failed == 0 and all(
         c["value"] is not None and c["value"] <= c["limit"] for c in checks.values()))
 
-    run = Run(record=record, trace=trace, model=model, peak=peak, chips=cell.chips,
-              setup_s=setup_s)
+    run = Run(record=record, trace=trace, model=model, family=cell.family, peak=peak,
+              chips=cell.chips, setup_s=setup_s)
     metrics = {}
     for m in cell.metrics:
         v = m.read(run)

@@ -1,7 +1,7 @@
-"""The loader finds each piece of a cell by file name; a new configuration,
-traffic mix, cell, driver and metric are added by adding files; a run
-refuses a host without the chips or peaks it needs; ``BENCHMARK.json`` keeps
-the benchmark's format."""
+"""The loader finds each piece of a cell by file name; a new architecture
+family, configuration, traffic mix, cell, driver and metric are added by
+adding files; a run refuses a host without the chips or peaks it needs;
+``BENCHMARK.json`` keeps the benchmark's format."""
 
 import json
 import os
@@ -40,6 +40,19 @@ def test_unknown_cell_and_device_kind_are_refused():
     with pytest.raises(KeyError, match="peaks.json"):
         loader.peaks_for("TPU v9 imaginary")
     assert loader.peaks_for("TPU v5 lite")["flops_bf16"] == 197e12
+
+
+@pytest.mark.parametrize("family", [None, "no_such_family"])
+def test_config_without_known_family_is_refused(tmp_path, family):
+    cfg = json.loads((ROOT / "chipbench" / "configs" / "qwen3-4b.json").read_text())
+    cfg.pop("family")
+    if family:
+        cfg["family"] = family
+    path = tmp_path / "chipbench" / "configs" / "toy.json"
+    path.parent.mkdir(parents=True)
+    path.write_text(json.dumps(cfg))
+    with pytest.raises(ValueError, match=re.escape(f"{path}: 'family'")):
+        loader.load_family(path)
 
 
 def test_run_refuses_host_without_tpu(capsys):
@@ -106,14 +119,20 @@ def test_benchmark_json_format():
 
 
 def test_new_cell_driver_metric_and_config_are_only_added_files(tmp_path):
-    """Copies the benchmark, adds one file of each kind, and runs the new
-    cell at rehearsal sizes; no file that was there is edited."""
+    """Copies the benchmark, adds one file of each kind, a family among them,
+    and runs the new cell at rehearsal sizes through the new family; no file
+    that was there is edited."""
     shutil.copytree(ROOT / "chipbench", tmp_path / "chipbench",
                     ignore=shutil.ignore_patterns("__pycache__", "tests"))
     pkg = tmp_path / "chipbench"
     before = {p: p.read_bytes() for p in pkg.rglob("*") if p.is_file()}
     cfg = json.loads((pkg / "configs" / "qwen3-4b.json").read_text())
-    cfg["name"] = "toy-config"
+    cfg["name"], cfg["family"] = "toy-config", "toy_family"
+    (pkg / "families" / "toy_family.py").write_text(
+        (pkg / "families" / "dense_gqa.py").read_text()
+        + "\n\n_sizes = sizes\n\n\ndef sizes(config, rehearse):\n"
+          "    print('[family] toy_family', flush=True)\n"
+          "    return _sizes(config, rehearse)\n")
     (pkg / "configs" / "toy-config.json").write_text(json.dumps(cfg))
     (pkg / "traffic" / "toy-traffic.json").write_text(json.dumps(
         {"arrivals": "poisson", "rate_rps": 10, "prompt_len": 16,
@@ -137,6 +156,7 @@ def test_new_cell_driver_metric_and_config_are_only_added_files(tmp_path):
 
     c = loader.load_cell("toy-cell", trace=False, root=tmp_path)
     assert c.config["name"] == "toy-config" and c.traffic["prompt_len"] == 16
+    assert c.family.__file__ == str(pkg / "families" / "toy_family.py")
     assert [m.name for m in c.metrics] == ["setup_s", "toy_calls"]
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=str(ROOT / "src"))
     proc = subprocess.run(
@@ -147,4 +167,5 @@ def test_new_cell_driver_metric_and_config_are_only_added_files(tmp_path):
     result = json.loads(proc.stdout.strip().splitlines()[-1])
     assert result["correct"] is True
     assert result["metrics"]["toy_calls"]["value"] >= 1
+    assert "[family] toy_family" in proc.stdout.splitlines()
     assert {p: p.read_bytes() for p in before} == before

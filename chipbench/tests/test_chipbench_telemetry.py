@@ -69,7 +69,7 @@ def _run(ok=(True, True, True), seconds=20.0):
     calls = [{"start": 0.5}, {"start": 3.0}]
     return Run(record={"seconds": seconds, "batch": 4, "prompt_len": 8, "requests": reqs,
                        "calls": calls},
-               trace=None, model=MODEL, peak=None, chips=1, setup_s=0.0)
+               trace=None, model=MODEL, family=None, peak=None, chips=1, setup_s=0.0)
 
 
 def _read(monkeypatch, snap, run):
