@@ -422,8 +422,17 @@ def attn_decode(
     *,
     cfg: ModelConfig,
     spec: LayerSpec,
+    layer: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Pytree]:
-    """x: (B, 1, d); idx: scalar int32, the position being generated."""
+    """x: (B, 1, d); idx: scalar int32, the position being generated.
+
+    Attends over the cache as it stands, with the new token's own key and
+    value joined in the softmax, then writes the token into the cache.  With
+    ``layer``, the cache arrays are the decode scan's stacked (n, B, H, L, D)
+    carry: the layer's cache is read where it lies and only the token's slot
+    is written back, so a step moves one token's bytes per layer, not the
+    layer's whole cache.
+    """
     theta = _rope_theta(cfg, spec)
     q = jnp.einsum("btd,dhk->bhtk", x, p["wq"])
     k = jnp.einsum("btd,dhk->bhtk", x, p["wk"])
@@ -434,39 +443,56 @@ def attn_decode(
     posv = jnp.full((1,), idx, jnp.int32)
     q = apply_rope(q, posv, theta)
     k = apply_rope(k, posv, theta)
-    ring = spec.window is not None and cache["k"].shape[2] == spec.window
-    cache = {
-        "k": _cache_write(cache["k"], k, idx, ring),
-        "v": _cache_write(cache["v"], v, idx, ring),
-    }
-    out = _decode_attention(q, cache, idx, spec)
+    # The token attends as the cache will hold it.
+    k = k.astype(cache["k"].dtype)
+    v = v.astype(cache["v"].dtype)
+    L = cache["k"].shape[-2]
+    ring = spec.window is not None and L == spec.window
+    if layer is None:
+        old = cache
+        cache = {"k": _cache_write(cache["k"], k, idx, ring),
+                 "v": _cache_write(cache["v"], v, idx, ring)}
+    else:
+        # A dynamic-update-slice, not a scatter: the scatter makes the
+        # compiler keep the stacked cache sequence-major and copy layers.
+        old = {n: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+               for n, a in cache.items()}
+        start = (layer, 0, 0, (idx % L) if ring else idx, 0)
+        cache = {"k": jax.lax.dynamic_update_slice(cache["k"], k[None], start),
+                 "v": jax.lax.dynamic_update_slice(cache["v"], v[None], start)}
+    out = _decode_attention(q, old["k"], old["v"], k, v, idx, spec)
     return jnp.einsum("bhtk,hkd->btd", out, p["wo"]), cache
 
 
-def _decode_attention(q, cache, idx, spec):
-    """One-token attention against a (possibly ring) cache."""
-    k, v = cache["k"], cache["v"]
+def _decode_attention(q, k, v, k_new, v_new, idx, spec):
+    """One-token attention at position ``idx`` over a (possibly ring) cache
+    that does not hold the token yet, and the token's own key and value."""
     B, Hkv, L, D = k.shape
     Hq = q.shape[1]
     G = Hq // Hkv
     qr = q.reshape(B, Hkv, G, 1, D)
-    s = jnp.einsum("bhgqd,bhkd->bhgqk", qr, k.astype(qr.dtype),
-                   preferred_element_type=jnp.float32) / math.sqrt(D)
-    ring = spec.window is not None and L == spec.window
+
+    def scores(keys):
+        return jnp.einsum("bhgqd,bhkd->bhgqk", qr, keys.astype(qr.dtype),
+                          preferred_element_type=jnp.float32) / math.sqrt(D)
+
     slots = jnp.arange(L, dtype=jnp.int32)
-    if ring:
+    if spec.window is not None and L == spec.window:
+        # Ring: the slot the token goes to holds position idx - L, and is
+        # excluded with the slots not yet written (kv_pos < 0).
         kv_pos = idx - jnp.mod(idx - slots, L)
-        mask = (kv_pos >= 0) & (kv_pos <= idx)
-        if spec.window is not None:
-            mask = mask & (kv_pos > idx - spec.window)
+        mask = (kv_pos >= 0) & (kv_pos < idx)
     else:
-        mask = slots <= idx
-        if spec.window is not None:
-            mask = mask & (slots > idx - spec.window)
-    s = jnp.where(mask[None, None, None, None, :], s, NEG_INF)
-    p_attn = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhgqk,bhkd->bhgqd", p_attn.astype(v.dtype), v,
-                     preferred_element_type=jnp.float32)
+        kv_pos = slots
+        mask = slots < idx
+    if spec.window is not None:
+        mask = mask & (kv_pos > idx - spec.window)
+    s = jnp.where(mask[None, None, None, None, :], scores(k), NEG_INF)
+    p_attn = jax.nn.softmax(jnp.concatenate([s, scores(k_new)], axis=-1), axis=-1)
+    out = (jnp.einsum("bhgqk,bhkd->bhgqd", p_attn[..., :L].astype(v.dtype), v,
+                      preferred_element_type=jnp.float32)
+           + jnp.einsum("bhgqk,bhkd->bhgqd", p_attn[..., L:].astype(v_new.dtype), v_new,
+                        preferred_element_type=jnp.float32))
     return out.reshape(B, Hq, 1, D).astype(q.dtype)
 
 

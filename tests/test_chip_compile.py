@@ -7,6 +7,8 @@ for a ``v5e:2x2`` topology description.  Nothing runs; this proves only that
 the chip's compiler accepts the programs and that they fit its memory.
 """
 
+import collections
+import dataclasses
 import math
 import os
 import re
@@ -142,14 +144,15 @@ def test_starcoder2_decode_step_fits_one_chip(starcoder2_decode):
 
 
 def test_starcoder2_decode_state_stays_in_place(starcoder2_decode):
-    """The step keeps its stacked cache sequence-major, not in the model's
-    batch-major order; every state leaf is aliased from input to output;
-    and the entry computation copies none of them, so there is no transpose
-    into the loop's layout and back around the step."""
+    """The step keeps its stacked cache in the model's own order, as the
+    loop reads each layer's cache in place and writes one token into it;
+    every state leaf is aliased from input to output; and the entry
+    computation copies none of them, so there is no transpose into the
+    loop's layout and back around the step."""
     compiled, orders, params, state = starcoder2_decode
     leaves = jax.tree.leaves(state)
     assert {a.shape for a in leaves} == {(30, 8, 2, 2048, 128)}
-    assert orders == [(0, 3, 2, 1, 4)] * len(leaves)
+    assert orders == [(0, 1, 2, 3, 4)] * len(leaves)
     text = compiled.as_text()
     aliased = {int(p) for p in re.findall(r"\{\d+\}: \((\d+), \{\}, \w+-alias\)",
                                           text.splitlines()[0])}
@@ -162,3 +165,60 @@ def test_starcoder2_decode_state_stays_in_place(starcoder2_decode):
     copied = [math.prod(int(d) for d in m.group(1).split(",") if d)
               for m in re.finditer(r"= \(?\w+\[([\d,]*)\]\S* copy(?:-start)?\(", entry)]
     assert copied and max(copied) < math.prod(leaves[0].shape)
+
+
+@pytest.fixture(scope="module")
+def qwen3_4b_decode(one_chip):
+    """The engine's decode step at qwen3-4b's published widths (hf
+    Qwen/Qwen3-4B: 36 layers, d_model 2560, 32 query heads on 8 KV heads of
+    128, SwiGLU 9728, tied head), batch 16 and ``max_len`` 1152, and the
+    shape of one layer's K cache."""
+    from repro import configs
+    from repro.models import params as P
+    from repro.serve.engine import compile_decode_step
+
+    cfg = dataclasses.replace(configs.get_config("qwen3-32b"), name="qwen3-4b", n_layers=36,
+                              d_model=2560, n_heads=32, d_ff=9728, tie_embeddings=True)
+    params = jax.tree.map(lambda s: _sds(s.shape, s.dtype, one_chip), P.abstract_params(cfg))
+    compiled, _ = compile_decode_step(cfg, params, batch=16, max_len=1152)
+    return compiled, (16, cfg.n_kv_heads, 1152, cfg.head_dim)
+
+
+def _computation(text, name):
+    """The instructions of computation ``name``: (name, result shapes,
+    opcode, operand names) each."""
+    start = re.search(rf"^%?{re.escape(name)} ", text, re.M).start()
+    body = text[start: text.index("\n}", start)]
+    out = []
+    for m in re.finditer(r"^\s*(?:ROOT )?%(\S+) = (.*?) ([a-z][\w-]*)\((.*)$", body, re.M):
+        shapes = [tuple(int(d) for d in dims.split(",") if d)
+                  for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(2))]
+        out.append((m.group(1), shapes, m.group(3), re.findall(r"%([\w.\-]+)", m.group(4))))
+    return out
+
+
+def test_decode_loop_writes_only_the_token(qwen3_4b_decode):
+    """Each attention layer reads its cache where it lies in the stacked
+    carry and writes back one token: the scan's body makes no copy, slice
+    or fusion result that holds a layer's K or V cache, every update of the
+    stacked cache is one token's size, and no temporary is as large as one
+    layer's K cache."""
+    compiled, layer = qwen3_4b_decode
+    text = compiled.as_text()
+    bodies = re.findall(r"while\(.*?body=%?([\w.\-]+)", text)
+    assert len(bodies) == 1
+    body = _computation(text, bodies[0])
+    shapes = {name: shapes for name, shapes, _, _ in body}
+
+    def holds_a_layer(shape):
+        return not collections.Counter(d for d in layer if d != 1) - collections.Counter(shape)
+
+    big = [(name, op, s) for name, ss, op, _ in body for s in ss
+           if op in ("fusion", "copy", "copy-start", "copy-done", "dynamic-slice")
+           and holds_a_layer(s)]
+    assert not big
+    token = math.prod(layer) // layer[2]
+    updates = [math.prod(shapes[operands[1]][0]) for name, ss, op, operands in body
+               if op == "dynamic-update-slice" and len(ss[0]) == 5 and holds_a_layer(ss[0])]
+    assert updates == [token, token]
+    assert compiled.memory_analysis().temp_size_in_bytes < math.prod(layer) * 2

@@ -76,6 +76,128 @@ def test_banded_attention_property(T, chunk, window, seed):
 
 
 # ---------------------------------------------------------------------------
+# One-token decode attention over a stacked cache
+# ---------------------------------------------------------------------------
+
+def _write_attend(p, x, cache, idx, *, cfg, spec):
+    """The slice-write-attend path's layer: the token written into the
+    layer's cache, then attended over with everything at or before it."""
+    theta = L._rope_theta(cfg, spec)
+    q = jnp.einsum("btd,dhk->bhtk", x, p["wq"])
+    k = jnp.einsum("btd,dhk->bhtk", x, p["wk"])
+    v = jnp.einsum("btd,dhk->bhtk", x, p["wv"])
+    if cfg.use_qk_norm:
+        q = L.rms_norm(q, p["q_norm"], cfg.norm_eps)
+        k = L.rms_norm(k, p["k_norm"], cfg.norm_eps)
+    posv = jnp.full((1,), idx, jnp.int32)
+    q = L.apply_rope(q, posv, theta)
+    k = L.apply_rope(k, posv, theta)
+    B, Hkv, Lc, D = cache["k"].shape
+    ring = spec.window is not None and Lc == spec.window
+    slot = idx % Lc if ring else idx
+    cache = {n: cache[n].at[:, :, slot].set(t[:, :, 0].astype(cache[n].dtype))
+             for n, t in (("k", k), ("v", v))}
+    qr = q.reshape(B, Hkv, q.shape[1] // Hkv, 1, D)
+    s = jnp.einsum("bhgqd,bhkd->bhgqk", qr, cache["k"].astype(qr.dtype),
+                   preferred_element_type=jnp.float32) / np.sqrt(D)
+    slots = jnp.arange(Lc)
+    kv_pos = idx - jnp.mod(idx - slots, Lc) if ring else slots
+    mask = (kv_pos >= 0) & (kv_pos <= idx)
+    if spec.window is not None:
+        mask = mask & (kv_pos > idx - spec.window)
+    pr = jax.nn.softmax(jnp.where(mask, s, L.NEG_INF), axis=-1)
+    out = jnp.einsum("bhgqk,bhkd->bhgqd", pr.astype(cache["v"].dtype), cache["v"],
+                     preferred_element_type=jnp.float32).reshape(q.shape).astype(q.dtype)
+    return jnp.einsum("bhtk,hkd->btd", out, p["wo"]), cache
+
+
+def _slice_write_attend(p, x, cache, idx, *, cfg, spec, layer=None):
+    """``attn_decode`` as it was: a stacked layer's whole cache sliced out,
+    written, attended over, and written back whole."""
+    if layer is None:
+        return _write_attend(p, x, cache, idx, cfg=cfg, spec=spec)
+    one = {n: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False) for n, a in cache.items()}
+    out, one = _write_attend(p, x, one, idx, cfg=cfg, spec=spec)
+    return out, {n: jax.lax.dynamic_update_index_in_dim(cache[n], one[n], layer, 0)
+                 for n in cache}
+
+
+def _attn_layer(window, n_layers=3, B=2, Hq=4, Hkv=2, D=16, d=32, max_len=20, seed=0):
+    """A float32 attention layer, a stacked bfloat16 cache of random values
+    (so that every slot the mask lets through, or not, is visible) and one
+    token's input."""
+    from repro.models.config import ATTN, LayerSpec, ModelConfig
+
+    cfg = ModelConfig(name="t", n_layers=n_layers, d_model=d, n_heads=Hq, n_kv_heads=Hkv,
+                      head_dim=D, d_ff=64, vocab_size=64, block_pattern=(LayerSpec(ATTN),),
+                      use_qk_norm=True, dtype="float32")
+    spec = LayerSpec(ATTN, window=window)
+    rng = np.random.default_rng(seed)
+
+    def draw(*shape, dtype=jnp.float32):
+        return jnp.asarray(rng.standard_normal(shape) / np.sqrt(shape[0]), dtype)
+
+    p = {"wq": draw(d, Hq, D), "wk": draw(d, Hkv, D), "wv": draw(d, Hkv, D),
+         "wo": draw(Hq, D, d), "q_norm": 1 + draw(D) / 4, "k_norm": 1 + draw(D) / 4}
+    Lc = min(max_len, window) if window else max_len
+    cache = {n: draw(n_layers, B, Hkv, Lc, D, dtype=jnp.bfloat16) * 4 for n in ("k", "v")}
+    return cfg, spec, p, cache, draw(B, 1, d) * 4
+
+
+@pytest.mark.parametrize("window,idx", [
+    (None, 0), (None, 7), (None, 19),        # dense cache
+    (24, 5), (24, 19),                       # a window wider than the cache: dense
+    (8, 3), (8, 7), (8, 8), (8, 13),         # ring: before, at and after it wraps
+])
+def test_attn_decode_in_place_matches_slice_write_attend(window, idx):
+    cfg, spec, p, cache, x = _attn_layer(window)
+    idx = jnp.asarray(idx, jnp.int32)
+    for layer in range(3):
+        layer = jnp.asarray(layer, jnp.int32)
+        got, got_cache = L.attn_decode(p, x, cache, idx, cfg=cfg, spec=spec, layer=layer)
+        ref, ref_cache = _slice_write_attend(p, x, cache, idx, cfg=cfg, spec=spec, layer=layer)
+        np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-6)
+        for n in ("k", "v"):
+            assert got_cache[n].dtype == ref_cache[n].dtype
+            np.testing.assert_array_equal(np.asarray(got_cache[n], np.float32),
+                                          np.asarray(ref_cache[n], np.float32))
+
+
+@pytest.mark.parametrize("arch", ["qwen3-32b", "gemma3-4b"])
+def test_decode_step_matches_slice_write_attend(arch, monkeypatch):
+    """Whole decode steps, from a prefill through a wrap of gemma3's 8-slot
+    rings (with its unstacked remainder layers): logits and every layer's
+    cache as the slice-write-attend path gives them."""
+    import dataclasses
+
+    from repro import configs
+    from repro.models import params as P
+    from repro.models import transformer as T
+
+    cfg = dataclasses.replace(configs.get_smoke(arch), dtype="float32")
+    params = P.init_params(cfg, jax.random.key(0))
+    toks = jnp.asarray(np.random.default_rng(0).integers(1, 256, (2, 5)), jnp.int32)
+    _, state = T.prefill(params, cfg, {"tokens": toks}, max_len=20, remat="none")
+
+    def steps(state):
+        step = jax.jit(lambda s, t, i: T.decode_step(params, cfg, s, {"tokens": t}, i))
+        cur, out = toks[:, -1:], []
+        for i in range(5, 15):
+            logits, state = step(state, cur, jnp.asarray(i, jnp.int32))
+            cur = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            out.append((logits, state))
+        return out
+
+    got = steps(state)
+    monkeypatch.setattr(L, "attn_decode", _slice_write_attend)
+    ref = steps(state)
+    for (g_logits, g_state), (r_logits, r_state) in zip(got, ref):
+        np.testing.assert_allclose(g_logits, r_logits, rtol=1e-5, atol=1e-5)
+        jax.tree.map(lambda a, b: np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6),
+                     g_state, r_state)
+
+
+# ---------------------------------------------------------------------------
 # SSD scan
 # ---------------------------------------------------------------------------
 
