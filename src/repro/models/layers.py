@@ -370,20 +370,6 @@ def attn_init_cache(
     }
 
 
-def _cache_write(cache_arr: jax.Array, new: jax.Array, idx: jax.Array, ring: bool):
-    """Write one token (B, H, 1, D) at logical position idx.
-
-    Uses a scatter (``.at[].set``) rather than dynamic-update-slice: with the
-    cache sequence dim sharded over the model axis, SPMD lowers a DUS at a
-    traced index to a masked select over the WHOLE local shard (measured 2x
-    cache traffic per layer on musicgen decode); a single-row scatter
-    partitions sparsely.
-    """
-    L = cache_arr.shape[2]
-    slot = (idx % L) if ring else idx
-    return cache_arr.at[:, :, slot].set(new[:, :, 0].astype(cache_arr.dtype))
-
-
 def attn_prefill_cache(
     p: Pytree, x: jax.Array, *, cfg: ModelConfig, spec: LayerSpec, cache: Pytree
 ) -> Pytree:
@@ -422,16 +408,15 @@ def attn_decode(
     *,
     cfg: ModelConfig,
     spec: LayerSpec,
-    layer: Optional[jax.Array] = None,
+    layer: jax.Array,
 ) -> Tuple[jax.Array, Pytree]:
-    """x: (B, 1, d); idx: scalar int32, the position being generated.
+    """x: (B, 1, d); idx: scalar int32, the position being generated;
+    ``cache``: stacked (n, B, H, L, D) arrays, of which this is layer
+    ``layer``.
 
-    Attends over the cache as it stands, with the new token's own key and
-    value joined in the softmax, then writes the token into the cache.  With
-    ``layer``, the cache arrays are the decode scan's stacked (n, B, H, L, D)
-    carry: the layer's cache is read where it lies and only the token's slot
-    is written back, so a step moves one token's bytes per layer, not the
-    layer's whole cache.
+    Attends over the layer's cache where it lies, with the new token's own
+    key and value joined in the softmax, then writes only the token's slot,
+    so a step moves one token's bytes per layer, not the layer's whole cache.
     """
     theta = _rope_theta(cfg, spec)
     q = jnp.einsum("btd,dhk->bhtk", x, p["wq"])
@@ -448,18 +433,13 @@ def attn_decode(
     v = v.astype(cache["v"].dtype)
     L = cache["k"].shape[-2]
     ring = spec.window is not None and L == spec.window
-    if layer is None:
-        old = cache
-        cache = {"k": _cache_write(cache["k"], k, idx, ring),
-                 "v": _cache_write(cache["v"], v, idx, ring)}
-    else:
-        # A dynamic-update-slice, not a scatter: the scatter makes the
-        # compiler keep the stacked cache sequence-major and copy layers.
-        old = {n: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
-               for n, a in cache.items()}
-        start = (layer, 0, 0, (idx % L) if ring else idx, 0)
-        cache = {"k": jax.lax.dynamic_update_slice(cache["k"], k[None], start),
-                 "v": jax.lax.dynamic_update_slice(cache["v"], v[None], start)}
+    # A dynamic-update-slice, not a scatter: the scatter makes the compiler
+    # keep the stacked cache sequence-major and copy layers.
+    old = {n: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+           for n, a in cache.items()}
+    start = (layer, 0, 0, (idx % L) if ring else idx, 0)
+    cache = {"k": jax.lax.dynamic_update_slice(cache["k"], k[None], start),
+             "v": jax.lax.dynamic_update_slice(cache["v"], v[None], start)}
     out = _decode_attention(q, old["k"], old["v"], k, v, idx, spec)
     return jnp.einsum("bhtk,hkd->btd", out, p["wo"]), cache
 

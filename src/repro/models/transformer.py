@@ -10,7 +10,7 @@ what production JAX LLM stacks (MaxText et al.) do.
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -215,6 +215,17 @@ def _ssd_fullseq_with_state(p, h, cfg):
     return y, {"S": S, "conv_x": conv_x, "conv_BC": conv_BC}
 
 
+def _at_layer(decode, state: Pytree, layer: jax.Array) -> Tuple[jax.Array, Pytree]:
+    """``decode`` on layer ``layer`` of a stacked ``state``, sliced out, and
+    its new state written back whole."""
+    one = jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+                       state)
+    out, new = decode(one)
+    return out, jax.tree.map(
+        lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n.astype(a.dtype), layer, 0),
+        state, new)
+
+
 def block_decode(
     p: Pytree,
     x: jax.Array,
@@ -223,23 +234,29 @@ def block_decode(
     *,
     cfg: ModelConfig,
     spec: LayerSpec,
-    layer: Optional[jax.Array] = None,
+    layer: jax.Array,
 ) -> Tuple[jax.Array, Pytree]:
-    """``layer``: an attention layer's index in a stacked cache (see
-    ``stack_decode``)."""
+    """One layer's decode step.  ``state`` is the layer kind's state stacked
+    on a leading layers axis (see ``stack_decode``), ``layer`` this layer's
+    index in it; returns the stacked state, updated.  An attention layer
+    reads its cache where it lies and writes one token; other states are
+    sliced out and written back whole."""
     h = L.rms_norm(x, p["ln1"]["scale"], cfg.norm_eps)
     if spec.kind == ATTN:
         mix, cache = L.attn_decode(p["attn"], h, state["cache"], idx, cfg=cfg, spec=spec,
                                    layer=layer)
         state = {"cache": cache}
     elif spec.kind == MLA:
-        mix, cache = L.mla_decode(p["attn"], h, state["cache"], idx, cfg=cfg)
+        mix, cache = _at_layer(lambda c: L.mla_decode(p["attn"], h, c, idx, cfg=cfg),
+                               state["cache"], layer)
         state = {"cache": cache}
     elif spec.kind == RGLRU:
-        mix, st = L.rglru_decode(p["rglru"], h, state["state"], cfg=cfg)
+        mix, st = _at_layer(lambda s: L.rglru_decode(p["rglru"], h, s, cfg=cfg),
+                            state["state"], layer)
         state = {"state": st}
     elif spec.kind == SSD:
-        mix, st = L.ssd_decode(p["ssd"], h, state["state"], cfg=cfg)
+        mix, st = _at_layer(lambda s: L.ssd_decode(p["ssd"], h, s, cfg=cfg),
+                            state["state"], layer)
         state = {"state": st}
     else:
         raise ValueError(spec.kind)
@@ -357,35 +374,16 @@ def stack_decode(
     if n_full:
         # The stacked decode state rides in the scan CARRY: a while-loop
         # carry is updated in place, where scan xs/ys would restack the
-        # whole cache every step.  Attention layers take the stacked cache
-        # and the layer index, attend over their layer where it lies and
-        # write back only the new token; slicing a layer's cache out and
-        # writing it back whole cost a copy of it each way, every step
-        # (≈10 of 34.6 ms a qwen3-4b step on a TPU v5e).  Other states
-        # are sliced out and written back whole.
+        # whole cache every step.  Each layer gets the stacked state and its
+        # index in it.
         def body(carry, xs):
             x, st = carry
             ps, layer = xs
             st = dict(st)
             x = C.constrain(x, ("batch", None, None))
             for i, spec in enumerate(cfg.block_pattern):
-                key = f"p{i}"
-                if spec.kind == ATTN:
-                    x, st[key] = block_decode(ps[key], x, st[key], idx, cfg=cfg, spec=spec,
-                                              layer=layer)
-                    continue
-                si = jax.tree.map(
-                    lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
-                    st[key],
-                )
-                x, ns = block_decode(ps[key], x, si, idx, cfg=cfg, spec=spec)
-                st[key] = jax.tree.map(
-                    lambda a, n: jax.lax.dynamic_update_index_in_dim(
-                        a, n.astype(a.dtype), layer, 0
-                    ),
-                    st[key],
-                    ns,
-                )
+                x, st[f"p{i}"] = block_decode(ps[f"p{i}"], x, st[f"p{i}"], idx, cfg=cfg,
+                                              spec=spec, layer=layer)
             return (x, st), None
 
         (x, ns), _ = jax.lax.scan(
@@ -394,13 +392,15 @@ def stack_decode(
         )
         new_state["period"] = ns
     if rem:
+        # A remainder layer's state is unstacked: decode it as a stack of one.
         new_state["rem"] = {}
         for i in range(rem):
             x, s = block_decode(
-                blocks["rem"][f"r{i}"], x, state["rem"][f"r{i}"], idx,
-                cfg=cfg, spec=cfg.block_pattern[i],
+                blocks["rem"][f"r{i}"], x,
+                jax.tree.map(lambda a: a[None], state["rem"][f"r{i}"]), idx,
+                cfg=cfg, spec=cfg.block_pattern[i], layer=0,
             )
-            new_state["rem"][f"r{i}"] = s
+            new_state["rem"][f"r{i}"] = jax.tree.map(lambda a: a[0], s)
     return x, new_state
 
 

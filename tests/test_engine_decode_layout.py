@@ -1,9 +1,9 @@
-"""The engine's decode step takes its state in the order of the device
-layout its compiler picks for it, and consumes it (donation).  Its greedy
-tokens are those of a plain loop of ``jax.jit(T.prefill)`` and
-``jax.jit(T.decode_step)``, with no reordering and no donation, for a dense
-cache and a ring cache, and stay so over consecutive ``generate`` calls: no
-wave reads a donated state."""
+"""The engine's decode step takes the state as the model's prefill gives it,
+in the model's own shapes, and consumes it (donation).  Its greedy tokens are
+those of a plain loop of ``jax.jit(T.prefill)`` and
+``jax.jit(T.decode_step)``, with no donation, for a dense cache and a ring
+cache, and stay so over consecutive ``generate`` calls: no wave reads a
+donated state."""
 
 import jax
 import jax.numpy as jnp
@@ -81,3 +81,13 @@ def test_decode_step_consumes_its_state(model):
     eng._decode(params, state, {"tokens": jnp.ones((BATCH, 1), jnp.int32)},
                 jnp.asarray(4, jnp.int32))
     assert all(a.is_deleted() for a in jax.tree.leaves(state))
+
+
+def test_prefill_state_has_the_model_shapes(model):
+    cfg, params = model
+    eng = Engine(cfg, params, batch=BATCH, max_len=MAX_LEN)
+    _, state = eng._prefill(params, {"tokens": jnp.ones((BATCH, 4), jnp.int32)})
+    want = jax.eval_shape(lambda: T.init_decode_state(cfg, BATCH, MAX_LEN))
+    assert jax.tree.structure(state) == jax.tree.structure(want)
+    assert [(a.shape, a.dtype) for a in jax.tree.leaves(state)] == [
+        (a.shape, a.dtype) for a in jax.tree.leaves(want)]

@@ -111,11 +111,9 @@ def _write_attend(p, x, cache, idx, *, cfg, spec):
     return jnp.einsum("bhtk,hkd->btd", out, p["wo"]), cache
 
 
-def _slice_write_attend(p, x, cache, idx, *, cfg, spec, layer=None):
+def _slice_write_attend(p, x, cache, idx, *, cfg, spec, layer):
     """``attn_decode`` as it was: a stacked layer's whole cache sliced out,
     written, attended over, and written back whole."""
-    if layer is None:
-        return _write_attend(p, x, cache, idx, cfg=cfg, spec=spec)
     one = {n: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False) for n, a in cache.items()}
     out, one = _write_attend(p, x, one, idx, cfg=cfg, spec=spec)
     return out, {n: jax.lax.dynamic_update_index_in_dim(cache[n], one[n], layer, 0)
